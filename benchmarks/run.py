@@ -100,6 +100,9 @@ def main() -> None:
                     help="comma-separated modules to exclude from the run")
     args = ap.parse_args()
     mods = select_modules(args.only, args.skip)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

@@ -94,7 +94,7 @@ INT8_ALLOWED_PRIMITIVES = frozenset(
         "pad",
         "copy",
         # structural plumbing that forwards operands untouched
-        "pjit",
+        "jit",
         "scan",
         "while",
         "cond",

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro import engine
 from repro.core.hash_families import PrefixTables
@@ -196,7 +196,7 @@ def build_local_indexes(
         mesh=mesh,
         in_specs=P(axes, None),
         out_specs=local_index_specs(mesh),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(data_sharded)
 
@@ -286,7 +286,7 @@ def sharded_index_query(
             mesh=mesh,
             in_specs=(local_index_specs(mesh), P(), P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         d, i, nc = fn(index_sharded, queries, weights)
         return ShardedQueryResult(dists=d, ids=i, n_candidates=nc)
@@ -308,7 +308,7 @@ def sharded_index_query(
         mesh=mesh,
         in_specs=(local_index_specs(mesh), local_delta_specs(mesh), P(axes), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     d, i, nc = fn(index_sharded, delta_sharded, tombstones_sharded, queries, weights)
     return ShardedQueryResult(dists=d, ids=i, n_candidates=nc)
@@ -379,7 +379,7 @@ def sharded_delta_insert(
         mesh=mesh,
         in_specs=(local_index_specs(mesh), local_delta_specs(mesh), P(axes), P(axes)),
         out_specs=(local_delta_specs(mesh), P(axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
     new_delta, ids_mat = fn(index_sharded, delta_sharded, rows_routed, valid_routed)
     j = jnp.arange(m, dtype=jnp.int32)
@@ -427,7 +427,7 @@ def sharded_tombstone(
         mesh=mesh,
         in_specs=(P(axes), P(), P(axes)),
         out_specs=P(axes),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(tombstones_sharded, gids, delta_fill)
 
@@ -466,7 +466,7 @@ def sharded_query(
         mesh=mesh,
         in_specs=(P(axes, None), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     d, i, nc = fn(data_sharded, queries, weights)
     return ShardedQueryResult(dists=d, ids=i, n_candidates=nc)

@@ -25,11 +25,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 # Block sizes (MXU-aligned). d-chunk keeps the one-hot tile ~ bn*dc*(M+1)*4 B
-# in VMEM: with bn=128, dc=64, M+1=65 that's ~2.1 MB; folded tile bh*dc*(M+1)*4
-# = 2.1 MB; comfortably inside the ~16 MB VMEM budget with double buffering.
+# in VMEM: with bn=128, dc=128, M+1=33 that's ~2.1 MB; folded tile
+# bh*dc*(M+1)*4 = 2.1 MB. The d-chunk is the lane dimension of the levels
+# and weights blocks, so it must be a multiple of 128 (Mosaic tiles the last
+# two block dims by (8, 128)).
 BN = 128  # points per block
 BH = 128  # hash functions per block
-BD = 64  # coordinates per reduction step
+BD = 128  # coordinates per reduction step
 
 
 def _project_kernel(levels_ref, weights_ref, folded_ref, out_ref, *, weighted: bool):

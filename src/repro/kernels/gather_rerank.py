@@ -7,13 +7,20 @@ query never needed. This kernel removes it: candidate ids are handed to
 Pallas as **scalar-prefetch** arguments (`pltpu.PrefetchScalarGridSpec`), so
 the BlockSpec index map — evaluated ahead of the grid step — points the
 pipeline's DMA engine directly at the needed `(1, d-chunk)` row of the
-(n, d) table in HBM. Each candidate's weighted |diff| partial sums accumulate
+table in HBM. Each candidate's weighted |diff| partial sums accumulate
 in a scalar scratch across d-chunks; the finished distance is folded into a
 per-query VMEM top-k buffer by replace-max insertion:
 
   grid (query i, candidate j, d-chunk kd):
     data block  (1, BDR)  @ row  min(ids[i, j], n-1)   — the gather
     out blocks  (1, KP)   @ i                          — running top-k
+
+Every per-row operand is an (m, 1, dp) view with the leading block dim
+squeezed, so each block's last two dims are (1, 128)/(1, KP): equal to the
+array's, as Mosaic requires of a block smaller than the (8, 128) tile. The
+scalar-prefetched ids live in SMEM (1 MiB on v5e), so a large (b, P) id
+array runs as a sequence of calls over bounded (query, candidate) tiles that
+carry the top-k buffer along (`_run_id_blocks`).
 
 Invalid candidates (padding, duplicates zapped by dedupe) carry the sentinel
 id n: the index map clamps them to a readable row and the merge step drops
@@ -59,6 +66,7 @@ concatenated-table result.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -68,18 +76,42 @@ from jax.experimental.pallas import tpu as pltpu
 BDR = 128  # coordinates per d-chunk (gather DMA granularity)
 KP_LANE = 128  # top-k buffer lane alignment
 CBLK = 8  # candidate rows gathered per grid step by the blocked schedule
+# Candidate ids one pallas_call scalar-prefetches into SMEM (128 KiB of
+# v5e's 1 MiB). Larger (b, P) id arrays run as a sequence of calls over
+# (query-block, candidate-block) tiles that carry the top-k buffer along.
+MAX_PREFETCH_IDS = 32 * 1024
 
 
-def _gather_rerank_kernel(ids_ref, row_ref, q_ref, w_ref, outd_ref, outi_ref, acc_ref, *, n: int):
+def _init_topk(initd_ref, initi_ref, outd_ref, outi_ref):
+    outd_ref[...] = initd_ref[...]
+    outi_ref[...] = initi_ref[...]
+
+
+def _insert(outd_ref, outi_ref, cid, dist, valid):
+    """Replace-max insertion of one finished candidate into the (1, KP)
+    buffer (first-occurrence argmax ⇒ +inf slots fill in order)."""
+    cur_d = outd_ref[...]  # (1, KP)
+    cur_i = outi_ref[...]
+    worst = jnp.max(cur_d)
+    slot = jnp.argmax(cur_d)
+    lane = jax.lax.broadcasted_iota(jnp.int32, cur_d.shape, 1)
+    put = (lane == slot) & valid & (dist < worst)
+    outd_ref[...] = jnp.where(put, dist, cur_d)
+    outi_ref[...] = jnp.where(put, cid, cur_i)
+
+
+def _gather_rerank_kernel(
+    ids_ref, row_ref, q_ref, w_ref, initd_ref, initi_ref, outd_ref, outi_ref, acc_ref,
+    *, n: int,
+):
     i = pl.program_id(0)
     j = pl.program_id(1)
     kd = pl.program_id(2)
     nd = pl.num_programs(2)
 
     @pl.when((j == 0) & (kd == 0))
-    def _init_topk():
-        outd_ref[...] = jnp.full_like(outd_ref, jnp.inf)
-        outi_ref[...] = jnp.full_like(outi_ref, -1)
+    def _():
+        _init_topk(initd_ref, initi_ref, outd_ref, outi_ref)
 
     partial = jnp.sum(w_ref[...] * jnp.abs(row_ref[...] - q_ref[...]))  # scalar
 
@@ -94,23 +126,12 @@ def _gather_rerank_kernel(ids_ref, row_ref, q_ref, w_ref, outd_ref, outi_ref, ac
     @pl.when(kd == nd - 1)
     def _merge():
         cid = ids_ref[i, j]
-        dist = acc_ref[0, 0]
-        cur_d = outd_ref[...]  # (1, KP)
-        cur_i = outi_ref[...]
-        worst = jnp.max(cur_d)
-        slot = jnp.argmax(cur_d)  # first-occurrence ⇒ fills +inf slots in order
-
-        @pl.when((cid < n) & (dist < worst))
-        def _insert():
-            lane = jax.lax.broadcasted_iota(jnp.int32, cur_d.shape, 1)
-            put = lane == slot
-            outd_ref[...] = jnp.where(put, dist, cur_d)
-            outi_ref[...] = jnp.where(put, cid, cur_i)
+        _insert(outd_ref, outi_ref, cid, acc_ref[0, 0], cid < n)
 
 
 def _gather_rerank2_kernel(
-    ids_ref, main_ref, delta_ref, q_ref, w_ref, outd_ref, outi_ref, acc_ref,
-    *, n_main: int, n_tot: int,
+    ids_ref, main_ref, delta_ref, q_ref, w_ref, initd_ref, initi_ref,
+    outd_ref, outi_ref, acc_ref, *, n_main: int, n_tot: int,
 ):
     """Two-segment variant: the grid pipelines BOTH segment tables as
     scalar-prefetch gather streams (each index map clamps the candidate id
@@ -122,9 +143,8 @@ def _gather_rerank2_kernel(
     nd = pl.num_programs(2)
 
     @pl.when((j == 0) & (kd == 0))
-    def _init_topk():
-        outd_ref[...] = jnp.full_like(outd_ref, jnp.inf)
-        outi_ref[...] = jnp.full_like(outi_ref, -1)
+    def _():
+        _init_topk(initd_ref, initi_ref, outd_ref, outi_ref)
 
     cid = ids_ref[i, j]
     part_m = jnp.sum(w_ref[...] * jnp.abs(main_ref[...] - q_ref[...]))  # scalar
@@ -141,44 +161,32 @@ def _gather_rerank2_kernel(
 
     @pl.when(kd == nd - 1)
     def _merge():
-        dist = acc_ref[0, 0]
-        cur_d = outd_ref[...]  # (1, KP)
-        cur_i = outi_ref[...]
-        worst = jnp.max(cur_d)
-        slot = jnp.argmax(cur_d)  # first-occurrence ⇒ fills +inf slots in order
-
-        @pl.when((cid < n_tot) & (dist < worst))
-        def _insert():
-            lane = jax.lax.broadcasted_iota(jnp.int32, cur_d.shape, 1)
-            put = lane == slot
-            outd_ref[...] = jnp.where(put, dist, cur_d)
-            outi_ref[...] = jnp.where(put, cid, cur_i)
+        _insert(outd_ref, outi_ref, cid, acc_ref[0, 0], cid < n_tot)
 
 
 def _make_blocked_kernel(cb: int, n_main: int, n_tot: int, two_seg: bool):
     """The block-coalesced kernel body: ``cb`` candidate rows per grid step.
 
     Ref layout (after the scalar-prefetch ids): ``cb`` main-row streams,
-    [``cb`` delta-row streams,] scales, q, w | outd, outi | (1, cb) SMEM
-    accumulator. The per-candidate math, accumulation order over d-chunks,
-    and top-k insertion order (global candidate order jb·cb + c) are all
-    IDENTICAL to the per-row kernels — same buffers, bit for bit — only the
-    DMA schedule changes: cb gather streams are in flight per step instead
-    of one."""
+    [``cb`` delta-row streams,] scales, q, w, init top-k | outd, outi |
+    (1, cb) SMEM accumulator. The per-candidate math, accumulation order
+    over d-chunks, and top-k insertion order (global candidate order
+    jb·cb + c) are all IDENTICAL to the per-row kernels — same buffers, bit
+    for bit — only the DMA schedule changes: cb gather streams are in
+    flight per step instead of one."""
 
     def kernel(ids_ref, *refs):
         nrow = cb * (2 if two_seg else 1)
         rows = refs[:nrow]
-        sc_ref, q_ref, w_ref, outd_ref, outi_ref, acc_ref = refs[nrow:]
+        sc_ref, q_ref, w_ref, initd_ref, initi_ref, outd_ref, outi_ref, acc_ref = refs[nrow:]
         i = pl.program_id(0)
         jb = pl.program_id(1)
         kd = pl.program_id(2)
         nd = pl.num_programs(2)
 
         @pl.when((jb == 0) & (kd == 0))
-        def _init_topk():
-            outd_ref[...] = jnp.full_like(outd_ref, jnp.inf)
-            outi_ref[...] = jnp.full_like(outi_ref, -1)
+        def _():
+            _init_topk(initd_ref, initi_ref, outd_ref, outi_ref)
 
         sc = sc_ref[...]  # (1, BDR) decode scales (exact ones when unscaled)
         for c in range(cb):
@@ -201,20 +209,108 @@ def _make_blocked_kernel(cb: int, n_main: int, n_tot: int, two_seg: bool):
         def _merge():
             for c in range(cb):
                 cid = ids_ref[i, jb * cb + c]
-                dist = acc_ref[0, c]
-                cur_d = outd_ref[...]  # (1, KP)
-                cur_i = outi_ref[...]
-                worst = jnp.max(cur_d)
-                slot = jnp.argmax(cur_d)  # first-occurrence ⇒ +inf slots fill in order
-                lane = jax.lax.broadcasted_iota(jnp.int32, cur_d.shape, 1)
-                put = (lane == slot) & (cid < n_tot) & (dist < worst)
-                outd_ref[...] = jnp.where(put, dist, cur_d)
-                outi_ref[...] = jnp.where(put, cid, cur_i)
+                _insert(outd_ref, outi_ref, cid, acc_ref[0, c], cid < n_tot)
 
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("k", "cb", "interpret"))
+def _row_view(x: jax.Array, dp: int) -> jax.Array:
+    """(m, d) -> (m, 1, dp) zero-padded rows. The unit middle axis makes a
+    single row a legal TPU block: ``(None, 1, BDR)`` has trailing dims
+    (1, 128), equal to / divisible by the array's, where a ``(1, BDR)``
+    block of an (m, dp) array is not (Mosaic tiles the last two dims by
+    (8, 128)). For f32 rows at d % 128 == 0 the view is a bitcast; int8 and
+    bf16 rows are laid out anew on each call (a speed PR's concern)."""
+    m, d = x.shape
+    return jnp.pad(x, ((0, 0), (0, dp - d))).reshape(m, 1, dp)
+
+
+def _id_blocks(b: int, P: int, max_ids: int, mult: int) -> tuple[int, int]:
+    """(query rows, candidate slots) per pallas_call: the whole (b, P) id
+    array when it fits ``max_ids``, else tiles of a multiple of 8 rows by
+    a multiple of ``mult`` slots holding at most ~``max_ids`` ids."""
+    if b * P <= max_ids:
+        return b, P
+    step = 8 * mult // math.gcd(8, mult)
+    pc = min(P, max(step, max_ids // 8 // step * step))
+    bq = min(-(-b // 8) * 8, max(8, max_ids // pc // 8 * 8))
+    return bq, pc
+
+
+def _run_id_blocks(call, ids, q3, w3, kp, sentinel, max_ids, mult=1):
+    """Drive ``call(ids_blk, q_blk, w_blk, initd, initi) -> (outd, outi)``
+    over bounded id tiles so no call's scalar prefetch outgrows SMEM.
+
+    Query blocks map independently (``lax.map``); within one, candidate
+    blocks run in order (``lax.scan``) with the unsorted top-k buffer
+    carried from call to call — every candidate meets the same buffer it
+    would have met in one monolithic call, so the result is bit-identical.
+    Padding slots carry ``sentinel`` (never inserted); padding queries are
+    sliced away. Returns the (b, kp) buffers."""
+    b, P = ids.shape
+    bq, pc = _id_blocks(b, P, max_ids, mult)
+    nb, nc = -(-b // bq), -(-P // pc)
+    ids = jnp.pad(ids, ((0, nb * bq - b), (0, nc * pc - P)), constant_values=sentinel)
+    q3 = jnp.pad(q3, ((0, nb * bq - b), (0, 0), (0, 0)))
+    w3 = jnp.pad(w3, ((0, nb * bq - b), (0, 0), (0, 0)))
+    init = (
+        jnp.full((bq, 1, kp), jnp.inf, jnp.float32),
+        jnp.full((bq, 1, kp), -1, jnp.int32),
+    )
+    if nb == 1 and nc == 1:
+        out_d, out_i = call(ids, q3, w3, *init)
+        return out_d[:b, 0], out_i[:b, 0]
+
+    def query_block(args):
+        ids_q, q_q, w_q = args  # (bq, nc·pc), (bq, 1, dp) x2
+
+        def cand_block(buf, ids_c):
+            return call(ids_c, q_q, w_q, *buf), None
+
+        blocks = ids_q.reshape(bq, nc, pc).swapaxes(0, 1)  # (nc, bq, pc)
+        buf, _ = jax.lax.scan(cand_block, init, blocks)
+        return buf
+
+    dp = q3.shape[-1]
+    out_d, out_i = jax.lax.map(
+        query_block,
+        (ids.reshape(nb, bq, nc * pc), q3.reshape(nb, bq, 1, dp), w3.reshape(nb, bq, 1, dp)),
+    )
+    return out_d.reshape(nb * bq, kp)[:b], out_i.reshape(nb * bq, kp)[:b]
+
+
+def _topk_call(kernel, tables, specs, grid_of, kp, acc_width, interpret, prefix=()):
+    """One bounded-id pallas_call: ``prefix`` operands (decode scales) and
+    the row ``tables`` follow the scalar-prefetched ids; q, w and the
+    carried top-k buffer come last. The buffer is aliased in place."""
+
+    def call(ids, q3, w3, initd, initi):
+        bq, pc = ids.shape
+        vec = pl.BlockSpec((None, 1, BDR), lambda i, j, kd, ids_ref: (i, 0, kd))
+        buf = pl.BlockSpec((None, 1, kp), lambda i, j, kd, ids_ref: (i, 0, 0))
+        n_in = 1 + len(tables) + len(prefix)  # ids, tables, prefix
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid_of(bq, pc, q3.shape[-1] // BDR),
+            in_specs=[*specs, vec, vec, buf, buf],
+            out_specs=(buf, buf),
+            scratch_shapes=[pltpu.SMEM((1, acc_width), jnp.float32)],
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=(
+                jax.ShapeDtypeStruct((bq, 1, kp), jnp.float32),
+                jax.ShapeDtypeStruct((bq, 1, kp), jnp.int32),
+            ),
+            input_output_aliases={n_in + 2: 0, n_in + 3: 1},
+            interpret=interpret,
+        )(ids, *tables, *prefix, q3, w3, initd, initi)
+
+    return call
+
+
+@functools.partial(jax.jit, static_argnames=("k", "cb", "interpret", "max_ids"))
 def gather_rerank_topk_pallas_blocked(
     data: jax.Array,
     ids: jax.Array,
@@ -226,6 +322,7 @@ def gather_rerank_topk_pallas_blocked(
     scales: jax.Array | None = None,
     cb: int = CBLK,
     interpret: bool = False,
+    max_ids: int = MAX_PREFETCH_IDS,
 ) -> tuple[jax.Array, jax.Array]:
     """Block-coalesced Pallas schedule: same contract as
     ``gather_rerank_topk_pallas`` plus quantized-storage decode.
@@ -242,64 +339,43 @@ def gather_rerank_topk_pallas_blocked(
     cap = 0 if delta is None else delta.shape[0]
     n_tot = n + cap
     kp = -min(k, P) % KP_LANE + min(k, P)
-    pd = -d % BDR
-    dp = d + pd
-    data_p = jnp.pad(data, ((0, 0), (0, pd)))  # encoded dtype preserved
-    q_p = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    w_p = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pd)))
+    dp = d + -d % BDR
     sc = jnp.ones((d,), jnp.float32) if scales is None else scales.astype(jnp.float32)
-    sc_p = jnp.pad(sc.reshape(1, d), ((0, 0), (0, pd)))
     pc = -P % cb
     ids_p = jnp.pad(ids.astype(jnp.int32), ((0, 0), (0, pc)), constant_values=n_tot)
-    grid = (b, ids_p.shape[1] // cb, dp // BDR)
 
     def _row_map(c):
         return lambda i, jb, kd, ids_ref: (
-            jnp.minimum(ids_ref[i, jb * cb + c], n - 1), kd,
+            jnp.minimum(ids_ref[i, jb * cb + c], n - 1), 0, kd,
         )
 
-    row_specs = [pl.BlockSpec((1, BDR), _row_map(c)) for c in range(cb)]
-    sc_spec = pl.BlockSpec((1, BDR), lambda i, jb, kd, ids_ref: (0, kd))
-    qw_spec = pl.BlockSpec((1, BDR), lambda i, jb, kd, ids_ref: (i, kd))
-    out_spec = pl.BlockSpec((1, kp), lambda i, jb, kd, ids_ref: (i, 0))
-    if delta is None:
-        tables = (data_p,) * cb
-        kernel = _make_blocked_kernel(cb, n_main=n, n_tot=n, two_seg=False)
-        in_specs = [*row_specs, sc_spec, qw_spec, qw_spec]
-    else:
+    specs = [pl.BlockSpec((None, 1, BDR), _row_map(c)) for c in range(cb)]
+    tables = (_row_view(data, dp),) * cb  # encoded dtype preserved
+    if delta is not None:
 
         def _delta_map(c):
             return lambda i, jb, kd, ids_ref: (
-                jnp.clip(ids_ref[i, jb * cb + c] - n, 0, cap - 1), kd,
+                jnp.clip(ids_ref[i, jb * cb + c] - n, 0, cap - 1), 0, kd,
             )
 
-        delta_p = jnp.pad(delta.astype(data.dtype), ((0, 0), (0, pd)))
-        delta_specs = [pl.BlockSpec((1, BDR), _delta_map(c)) for c in range(cb)]
-        tables = (data_p,) * cb + (delta_p,) * cb
-        kernel = _make_blocked_kernel(cb, n_main=n, n_tot=n_tot, two_seg=True)
-        in_specs = [*row_specs, *delta_specs, sc_spec, qw_spec, qw_spec]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(out_spec, out_spec),
-        scratch_shapes=[pltpu.SMEM((1, cb), jnp.float32)],
+        specs += [pl.BlockSpec((None, 1, BDR), _delta_map(c)) for c in range(cb)]
+        tables += (_row_view(delta.astype(data.dtype), dp),) * cb
+    specs.append(pl.BlockSpec((None, 1, BDR), lambda i, jb, kd, ids_ref: (0, 0, kd)))
+    kernel = _make_blocked_kernel(cb, n_main=n, n_tot=n_tot, two_seg=delta is not None)
+    call = _topk_call(
+        kernel, tables, specs, lambda bq, p, nd: (bq, p // cb, nd), kp, cb, interpret,
+        prefix=(_row_view(sc.reshape(1, d), dp),),
     )
-    out_d, out_i = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((b, kp), jnp.float32),
-            jax.ShapeDtypeStruct((b, kp), jnp.int32),
-        ),
-        interpret=interpret,
-    )(ids_p, *tables, sc_p, q_p, w_p)
+    out_d, out_i = _run_id_blocks(
+        call, ids_p, _row_view(queries.astype(jnp.float32), dp),
+        _row_view(weights.astype(jnp.float32), dp), kp, n_tot, max_ids, mult=cb,
+    )
     from repro.kernels.ref import _topk_ascending
 
     return _topk_ascending(out_d, out_i, k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "interpret", "max_ids"))
 def gather_rerank_topk_pallas(
     data: jax.Array,
     ids: jax.Array,
@@ -310,6 +386,7 @@ def gather_rerank_topk_pallas(
     delta: jax.Array | None = None,
     scales: jax.Array | None = None,
     interpret: bool = False,
+    max_ids: int = MAX_PREFETCH_IDS,
 ) -> tuple[jax.Array, jax.Array]:
     """data (n, d), ids (b, P) int32 (>= n ⇒ invalid), queries/weights (b, d)
     -> ((b, k) ascending dists, (b, k) ids). With ``delta`` (cap, d), ids
@@ -317,60 +394,47 @@ def gather_rerank_topk_pallas(
 
     Quantized storage (non-f32 ``data`` and/or ``scales``) routes to the
     block-coalesced schedule, which gathers the encoded rows and decodes
-    in-register; the f32 path below is the pre-quantization program,
-    untouched."""
+    in-register; the f32 path below is the pre-quantization program.
+    ``max_ids`` bounds the ids one call prefetches (see ``_run_id_blocks``)."""
     if data.dtype != jnp.float32 or scales is not None:
         return gather_rerank_topk_pallas_blocked(
             data, ids, queries, weights, k,
-            delta=delta, scales=scales, interpret=interpret,
+            delta=delta, scales=scales, interpret=interpret, max_ids=max_ids,
         )
     n, d = data.shape
     b, P = ids.shape
     kp = -min(k, P) % KP_LANE + min(k, P)
-    pd = -d % BDR
-    data_p = jnp.pad(data.astype(jnp.float32), ((0, 0), (0, pd)))
-    q_p = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    w_p = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pd)))
-    dp = d + pd
-    grid = (b, P, dp // BDR)
+    dp = d + -d % BDR
     row_spec = pl.BlockSpec(
-        (1, BDR), lambda i, j, kd, ids_ref: (jnp.minimum(ids_ref[i, j], n - 1), kd)
+        (None, 1, BDR),
+        lambda i, j, kd, ids_ref: (jnp.minimum(ids_ref[i, j], n - 1), 0, kd),
     )
-    qw_spec = pl.BlockSpec((1, BDR), lambda i, j, kd, ids_ref: (i, kd))
-    out_spec = pl.BlockSpec((1, kp), lambda i, j, kd, ids_ref: (i, 0))
     if delta is None:
-        in_specs = [row_spec, qw_spec, qw_spec]
+        specs = [row_spec]
         kernel = functools.partial(_gather_rerank_kernel, n=n)
-        tables = (data_p,)
+        tables = (_row_view(data, dp),)
+        n_tot = n
     else:
         cap = delta.shape[0]
+        n_tot = n + cap
+        delta_spec = pl.BlockSpec(
+            (None, 1, BDR),
+            lambda i, j, kd, ids_ref: (jnp.clip(ids_ref[i, j] - n, 0, cap - 1), 0, kd),
+        )
+        specs = [row_spec, delta_spec]
+        kernel = functools.partial(_gather_rerank2_kernel, n_main=n, n_tot=n_tot)
         # round delta rows through the main table's dtype first — the same
         # cast every other schedule (and the old concat path) applies, so
         # mixed-dtype segments rerank identically across backends
-        delta_p = jnp.pad(delta.astype(data.dtype).astype(jnp.float32), ((0, 0), (0, pd)))
-        delta_spec = pl.BlockSpec(
-            (1, BDR),
-            lambda i, j, kd, ids_ref: (jnp.clip(ids_ref[i, j] - n, 0, cap - 1), kd),
+        tables = (
+            _row_view(data, dp),
+            _row_view(delta.astype(data.dtype).astype(jnp.float32), dp),
         )
-        in_specs = [row_spec, delta_spec, qw_spec, qw_spec]
-        kernel = functools.partial(_gather_rerank2_kernel, n_main=n, n_tot=n + cap)
-        tables = (data_p, delta_p)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(out_spec, out_spec),
-        scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
+    call = _topk_call(kernel, tables, specs, lambda bq, p, nd: (bq, p, nd), kp, 1, interpret)
+    out_d, out_i = _run_id_blocks(
+        call, ids.astype(jnp.int32), _row_view(queries.astype(jnp.float32), dp),
+        _row_view(weights.astype(jnp.float32), dp), kp, n_tot, max_ids,
     )
-    out_d, out_i = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((b, kp), jnp.float32),
-            jax.ShapeDtypeStruct((b, kp), jnp.int32),
-        ),
-        interpret=interpret,
-    )(ids.astype(jnp.int32), *tables, q_p, w_p)
     # buffer is the kp smallest, unsorted — order + trim to k outside the kernel
     from repro.kernels.ref import _topk_ascending
 

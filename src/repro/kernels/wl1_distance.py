@@ -80,11 +80,11 @@ def wl1_scan_pallas(
 
 def _rerank_kernel(pts_ref, q_ref, w_ref, out_ref):
     kd = pl.program_id(2)
-    pts = pts_ref[...]  # (1, BC, BDV)
+    pts = pts_ref[...]  # (BC, BDV)
     q = q_ref[...]  # (1, BDV)
     w = w_ref[...]  # (1, BDV)
-    diff = jnp.abs(pts[0] - q[0][None, :])  # (BC, BDV)
-    partial = jnp.sum(w[0][None, :] * diff, axis=-1)[None, :]  # (1, BC)
+    diff = jnp.abs(pts - q)  # (BC, BDV)
+    partial = jnp.sum(w * diff, axis=-1)[None, :]  # (1, BC)
 
     @pl.when(kd == 0)
     def _init():
@@ -99,13 +99,16 @@ def _rerank_kernel(pts_ref, q_ref, w_ref, out_ref):
 def wl1_rerank_pallas(
     pts: jax.Array, queries: jax.Array, weights: jax.Array, *, interpret: bool = False
 ) -> jax.Array:
-    """pts (b, C, d), queries (b, d), weights (b, d) -> (b, C) float32."""
+    """pts (b, C, d), queries (b, d), weights (b, d) -> (b, C) float32.
+
+    Per-query vectors ride a unit middle axis ((b, 1, ·) views, the leading
+    block dim squeezed) so every block's trailing dims are TPU-tileable."""
     b, C, d = pts.shape
     pc = -C % BC
     pd = -d % BDV
     pts_p = jnp.pad(pts.astype(jnp.float32), ((0, 0), (0, pc), (0, pd)))
-    q_p = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))
-    w_p = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pd)))
+    q_p = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, pd)))[:, None, :]
+    w_p = jnp.pad(weights.astype(jnp.float32), ((0, 0), (0, pd)))[:, None, :]
     cp = C + pc
     dp = d + pd
     grid = (b, cp // BC, dp // BDV)
@@ -113,12 +116,12 @@ def wl1_rerank_pallas(
         _rerank_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, BC, BDV), lambda i, j, k: (i, j, k)),
-            pl.BlockSpec((1, BDV), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, BDV), lambda i, j, k: (i, k)),
+            pl.BlockSpec((None, BC, BDV), lambda i, j, k: (i, j, k)),
+            pl.BlockSpec((None, 1, BDV), lambda i, j, k: (i, 0, k)),
+            pl.BlockSpec((None, 1, BDV), lambda i, j, k: (i, 0, k)),
         ],
-        out_specs=pl.BlockSpec((1, BC), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, cp), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, BC), lambda i, j, k: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, cp), jnp.float32),
         interpret=interpret,
     )(pts_p, q_p, w_p)
-    return out[:, :C]
+    return out[:, 0, :C]

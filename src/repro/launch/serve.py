@@ -45,6 +45,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def serve_alsh(args):
     import dataclasses
@@ -434,6 +436,7 @@ def main():
     ap.add_argument("--max-queue", type=int, default=256,
                     help="broker mode: admission queue bound (overflow sheds)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.mode == "alsh":
         serve_alsh(args)
     elif args.mode == "stream":

@@ -31,6 +31,8 @@ import argparse
 import json
 import os
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def build_space(args) -> "ScanSpace":
     """The CLI axes as a declarative ScanSpace (shared with tests)."""
@@ -88,10 +90,12 @@ def main(argv=None) -> int:
                     help="held-out queries per trial")
     ap.add_argument("--seed", type=int, default=0, help="scan base seed")
     ap.add_argument("--workers", type=int, default=0,
-                    help="worker processes (0/1 = inline)")
+                    help="worker processes (0/1 = inline); CPU only — a TPU "
+                         "belongs to one process, so >1 is refused there")
     ap.add_argument("--max-trials", type=int, default=None,
                     help="stop after this many NEW trials (resume later)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     from repro.tuner import TuningTable, build_table, run_scan, scan_is_complete
 

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig, MoEConfig
@@ -180,7 +180,7 @@ def moe_ffn_ep_shardmap(params: dict, x: jax.Array, cfg: ModelConfig,
             P(batch_axes, None, None),
         ),
         out_specs=P(batch_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     we = params["experts"]
     # transient FSDP gather (storage stays (EP x FSDP)-sharded; see moe_specs)
@@ -303,7 +303,7 @@ def moe_ffn_a2a_shardmap(params: dict, x: jax.Array, cfg: ModelConfig,
         mesh=mesh,
         in_specs=(P(), P(ep_axis), P(ep_axis), P(ep_axis), P(batch_axes, None, None)),
         out_specs=P(batch_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     we = params["experts"]
     w_up = maybe_shard(we["w_up"], EP, None, None)

@@ -44,7 +44,22 @@ from repro.tuner.space import (
     profile_weights,
 )
 
-__all__ = ["TrialStore", "run_trial", "run_scan", "resolve_width", "scan_is_complete", "trial_cost"]
+__all__ = [
+    "TrialStore",
+    "WorkersOnChipError",
+    "run_trial",
+    "run_scan",
+    "resolve_width",
+    "scan_is_complete",
+    "trial_cost",
+]
+
+
+class WorkersOnChipError(RuntimeError):
+    """``workers > 1`` on a TPU backend: a TPU belongs to one process at a
+    time, so spawned workers would fail or hang waiting for the chip the
+    parent already holds. Run trials inline (``workers=0``) there."""
+
 
 # relative cost of a probed (table, probe, slot) vs one reranked candidate —
 # mirrors Planner.slot_cost so scan costs and plan costs rank identically
@@ -116,6 +131,7 @@ def run_trial(trial_dict: dict, real_data=None) -> dict:
     from repro.api import Index, IndexConfig, PlannedSpec, QuerySpec
     from repro.core.transforms import BoundedSpace
     from repro.distance import recall_at_k
+    from repro.launch.mesh import make_mesh
 
     trial = TrialSpec.from_dict(trial_dict)
     rec = {"trial_id": trial.trial_id, "trial": trial.to_dict(), "status": "ok"}
@@ -158,7 +174,7 @@ def run_trial(trial_dict: dict, real_data=None) -> dict:
     )
     handle = index
     if trial.shards > 1:
-        handle = index.shard(jax.make_mesh((trial.shards,), ("data",)))
+        handle = index.shard(make_mesh((trial.shards,), ("data",)))
 
     res = handle.query(qs, ws, spec)
     exact = handle.query(qs, ws, QuerySpec(k=trial.k, mode="exact"))
@@ -320,12 +336,22 @@ def run_scan(
       store_path: JSONL trial store — created with a space header if absent,
         resumed (completed ids skipped) if present.
       workers: 0/1 runs trials inline; N > 1 fans out over N spawned worker
-        processes (each with its own jax runtime).
+        processes (each with its own jax runtime) — CPU backends only:
+        on a TPU it raises :class:`WorkersOnChipError`.
       real_data: (rows, d) array backing ``source="sampled"`` profiles.
       max_trials: stop after this many NEW completions (crash/resume drills
         and budgeted incremental scans); None runs the grid dry.
       log: optional ``print``-like progress callback.
     """
+    if workers > 1:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise WorkersOnChipError(
+                f"run_scan(workers={workers}) on a TPU backend: the chip "
+                f"belongs to one process, so spawned workers cannot use it "
+                f"— run with workers=0 (trials inline in this process)"
+            )
     trials = space.trials()
     store = TrialStore(store_path)
     store.repair()  # drop a torn trailing line before appending below it

@@ -34,7 +34,8 @@ def test_sharded_alsh_matches_global_bruteforce():
         from repro.core.distributed import sharded_query
         from repro.distance import brute_force_nn
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         n, d, M, k = 4096, 12, 16, 10
         key = jax.random.PRNGKey(0)
         data = jax.random.uniform(key, (n, d))
@@ -78,7 +79,8 @@ def test_facade_shard_prebuilt_matches_oneshot():
         from repro.distance import brute_force_nn
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         n, d, M, k = 2048, 12, 16, 5
         key = jax.random.PRNGKey(0)
         data = jax.random.uniform(key, (n, d))
@@ -115,7 +117,8 @@ def test_mutable_lifecycle_save_load_shard_parity():
         import jax, jax.numpy as jnp, numpy as np
         from repro.api import Index, IndexConfig, QuerySpec, UpdateSpec, BoundedSpace
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         n, d, k = 512, 8, 7
         key = jax.random.PRNGKey(0)
         data = jax.random.uniform(jax.random.fold_in(key, 0), (n, d))
@@ -175,7 +178,8 @@ def test_train_step_on_small_production_mesh():
         from repro.runtime.train_step import (init_train_state, make_train_step,
                                               train_state_specs, batch_pytree_specs)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         bundle = get_bundle("qwen3-8b")
         mcfg = dataclasses.replace(reduced_model(bundle.model), n_units=2, n_layers=2,
                                    n_heads=4, n_kv_heads=2, d_model=64)
@@ -218,7 +222,8 @@ def test_decode_step_on_small_mesh():
         from repro.models.sharding import use_mesh, sanitize_spec_tree
         from repro.runtime.serve_step import make_decode_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         mcfg = reduced_model(get_bundle("gemma3-1b").model)
         with use_mesh(mesh):
             params = models.init_params(jax.random.PRNGKey(0), mcfg)
@@ -254,7 +259,8 @@ def test_moe_ep_shardmap_matches_gspmd():
         from repro.models import moe
         from repro.models.sharding import use_mesh, set_policy
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         bundle = get_bundle("llama4-scout-17b-16e")
         mcfg = reduced_model(bundle.model)  # 4 experts, capacity >= T
         key = jax.random.PRNGKey(0)
@@ -286,7 +292,8 @@ def test_moe_a2a_shardmap_matches_gspmd():
         from repro.models import moe
         from repro.models.sharding import use_mesh, set_policy
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         bundle = get_bundle("llama4-scout-17b-16e")
         mcfg = reduced_model(bundle.model)  # 4 experts, capacity >= T (no drops)
         key = jax.random.PRNGKey(0)

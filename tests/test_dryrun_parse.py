@@ -68,7 +68,8 @@ def test_real_module_scales_with_layers():
         from repro.runtime.train_step import (init_train_state, make_train_step,
                                               train_state_specs, batch_pytree_specs)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         vals = {}
         for n_units in (1, 4):
             bundle = get_bundle("qwen3-8b")

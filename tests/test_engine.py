@@ -48,6 +48,7 @@ from repro.core.index import (
 )
 from repro.core.multiprobe import multiprobe_keys_for
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 N = 400
 D = 8
@@ -457,7 +458,7 @@ def test_sharded_query_validates_like_single_host(rng):
     Index.query — malformed inputs raise the named ValueError, not a
     shard_map trace error."""
     data, _, q, w = _problem(rng)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sharded = Index.build(jax.random.fold_in(rng, 9), data, _cfg()).shard(mesh)
     with pytest.raises(ValueError, match="queries"):
         sharded.query(q[:, :-1], w, QuerySpec(k=3))
@@ -480,7 +481,8 @@ def test_sharded_engine_matches_single_host():
         import jax, jax.numpy as jnp, numpy as np
         from repro.api import Index, IndexConfig, QuerySpec, UpdateSpec, BoundedSpace
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         n, d, k = 512, 8, 7
         key = jax.random.PRNGKey(0)
         data = jax.random.uniform(jax.random.fold_in(key, 0), (n, d))

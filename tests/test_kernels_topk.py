@@ -79,6 +79,39 @@ def test_gather_rerank_topk_matches_ref(n, b, P, d, k, impl):
     assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
 
 
+@pytest.mark.parametrize("storage", ["f32", "two_segment", "int8", "bf16"])
+@pytest.mark.parametrize("max_ids", [64, 200])
+def test_gather_rerank_id_tiles_match_one_call(storage, max_ids):
+    """Ids split into bounded (query, candidate) tiles — what keeps a large
+    batch's scalar prefetch inside SMEM — carry the top-k buffer from tile
+    to tile: bit-identical to one call over all ids, and equal to the ref."""
+    from repro import quant
+
+    key = jax.random.PRNGKey(max_ids)
+    n, b, P, d, k = 90, 11, 70, 20, 6
+    data = jax.random.normal(key, (n, d))
+    q = jax.random.normal(jax.random.fold_in(key, 1), (b, d))
+    w = jax.random.normal(jax.random.fold_in(key, 2), (b, d))
+    kw = {}
+    if storage == "two_segment":
+        kw["delta"] = jax.random.normal(jax.random.fold_in(key, 4), (13, d))
+        hi = n + 13 + 3
+    else:
+        hi = n + 20
+    if storage in ("int8", "bf16"):
+        data, kw["scales"] = quant.get_codec(storage).encode(data)
+    ids = jax.random.randint(jax.random.fold_in(key, 3), (b, P), 0, hi)
+    ids = jnp.minimum(ids, hi - 3).astype(jnp.int32)  # some invalid sentinels
+    assert b * P > max_ids  # the tiled path runs
+    got = gather_rerank_topk_pallas(data, ids, q, w, k, interpret=True, max_ids=max_ids, **kw)
+    whole = gather_rerank_topk_pallas(data, ids, q, w, k, interpret=True, max_ids=b * P, **kw)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(whole[0]))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(whole[1]))
+    want = ops.gather_rerank_topk(data, ids, q, w, k, force="ref", **kw)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=1e-5, atol=1e-5)
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
 @pytest.mark.parametrize("impl", ["interpret", "chunked", "ref"])
 def test_gather_rerank_all_invalid(impl):
     """A query whose every candidate slot is padding returns (+inf, -1)."""

@@ -26,7 +26,8 @@ def test_pipeline_forward_and_grads_match_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from repro.runtime.pipeline import pipeline_apply, pipeline_loss
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         P_stages, n_micro, mb, dim = 4, 8, 2, 16
         key = jax.random.PRNGKey(0)
         Ws = jax.random.normal(key, (P_stages, dim, dim)) / dim**0.5

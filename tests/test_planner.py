@@ -28,6 +28,7 @@ from repro.api import (
     QuerySpec,
 )
 from repro.distance import recall_at_k
+from repro.launch.mesh import make_mesh
 
 QUALITY = QualitySpec(k=5, recall_target=0.8, calibration_queries=16)
 
@@ -275,7 +276,7 @@ def test_v2_directories_still_load(rng, tmp_path):
 
 def test_plans_survive_shard(planned_index, rng_module):
     _, q, w = _problem(rng_module, salt=0)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sharded = planned_index.shard(mesh)
     assert sharded.plans == planned_index.plans
     res_q = sharded.query(q, w, QUALITY)
@@ -286,7 +287,7 @@ def test_plans_survive_shard(planned_index, rng_module):
 def test_sharded_rejects_unplanned_quality(rng):
     data, q, w = _problem(rng, salt=50)
     index = Index.build(jax.random.fold_in(rng, 59), data, _cfg())
-    sharded = index.shard(jax.make_mesh((1,), ("data",)))
+    sharded = index.shard(make_mesh((1,), ("data",)))
     with pytest.raises(ValueError, match="BEFORE index.shard"):
         sharded.query(q, w, QUALITY)
 
@@ -295,7 +296,7 @@ def test_sharded_rejects_unreachable_n_probes(rng):
     """The sharded facade applies the same probe-reach gate as Index.query."""
     data, q, w = _problem(rng, salt=55)
     index = Index.build(jax.random.fold_in(rng, 58), data, _cfg(K=4))
-    sharded = index.shard(jax.make_mesh((1,), ("data",)))
+    sharded = index.shard(make_mesh((1,), ("data",)))
     with pytest.raises(ValueError, match="distinct probe keys reachable"):
         sharded.query(q, w, QuerySpec(k=3, mode="multiprobe", n_probes=6, max_flips=1))
 

@@ -11,6 +11,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_bundle
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import DataConfig, SyntheticStream
+from repro.launch.mesh import make_mesh
 from repro.models.sharding import sanitize_spec
 from repro.optim import (
     adamw_update,
@@ -127,7 +128,7 @@ def test_stream_modalities():
 
 
 def test_sanitize_spec_divisibility():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))  # single device: sizes 1
+    mesh = make_mesh((1, 1), ("data", "model"))  # single device: sizes 1
     s = sanitize_spec(P("data", "model"), (8, 8), mesh)
     assert s == P("data", "model")  # size-1 axes always divide
 
@@ -142,11 +143,12 @@ def test_sanitize_spec_drops_nondivisible():
         import jax
         from jax.sharding import PartitionSpec as P
         from repro.models.sharding import sanitize_spec
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         # dim 8 % 4 == 0 keeps "model"; dim 3 % 2 != 0 drops "data"
         assert sanitize_spec(P("data", "model"), (3, 8), mesh) == P(None, "model")
         # tuple degrades greedily: ("pod","data") -> prefix that divides
-        mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh2 = make_mesh((2, 4), ("pod", "data"))
         assert sanitize_spec(P(("pod", "data")), (2,), mesh2) == P(("pod",))
         assert sanitize_spec(P(("pod", "data")), (8,), mesh2) == P(("pod", "data"))
         # unknown axis names dropped

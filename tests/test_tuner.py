@@ -260,6 +260,18 @@ def test_worker_pool_matches_inline(tmp_path):
             assert a[key] == b[key], key
 
 
+def test_worker_pool_refused_on_tpu(tmp_path, monkeypatch):
+    """A TPU belongs to one process: the parent holds it, so spawned
+    workers could not reach it. workers > 1 is refused there, by name,
+    before any store is touched."""
+    from repro.tuner.scan import WorkersOnChipError
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(WorkersOnChipError, match="workers=2"):
+        run_scan(SPACE, tmp_path / "trials.jsonl", workers=2)
+    assert not (tmp_path / "trials.jsonl").exists()
+
+
 # ---------------------------------------------------------------------------
 # table: artifact + lookup
 # ---------------------------------------------------------------------------

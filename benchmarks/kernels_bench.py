@@ -2,7 +2,7 @@
 interpret-mode validation cost, plus the fused-vs-unfused probe-tail rows
 that track the PR-over-PR perf trajectory (benchmarks/run.py snapshots them
 into BENCH_kernels.json). On TPU the ops.py dispatcher switches to the
-compiled Pallas kernels; the dry-run roofline covers their cost model.
+compiled Pallas kernels.
 
 Fused-tail methodology: the "3-step path" is the seed's candidate tail as
 separately dispatched kernel stages — gather the (b, P, d) candidate tensor,

@@ -2,10 +2,9 @@
 paper — no experimental tables — so benchmarks validate its equations and
 complexity claims; see DESIGN.md §1 "Validation targets").
 
-    PYTHONPATH=src python -m benchmarks.run [--only collision,...] [--skip roofline,...]
+    PYTHONPATH=src python -m benchmarks.run [--only collision,...] [--skip recall,...]
 
-Prints ``name,us_per_call,derived`` CSV. The roofline rows summarize the
-compiled dry-run artifacts if present (run repro.launch.dryrun first).
+Prints ``name,us_per_call,derived`` CSV.
 
 The kernel rows are additionally snapshotted to ``BENCH_kernels.json``,
 the mutable-lifecycle rows to ``BENCH_updates.json``, the planner
@@ -41,7 +40,6 @@ MODULES = [
     "quant_bench",  # quantized tier: memory ratio, latency, recall delta
     "earlyexit_bench",  # adaptive probing: tables probed + speedup vs full L
     "analysis_bench",  # static-analysis gate: lint/trace cost + budget numbers
-    "roofline",  # dry-run roofline summaries (if results exist)
 ]
 
 # convenience aliases accepted by --only/--skip

@@ -42,13 +42,15 @@ tests/test_lifecycle.py).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import engine
+from repro import engine, obs
+from repro.analysis.retrace_guard import engine_cache_size
 from repro.api.spec import PlannedSpec, QualitySpec, QuerySpec, UpdateSpec
 from repro.core.families import n_flip_subsets
 from repro.core.index import (
@@ -60,6 +62,8 @@ from repro.core.index import (
     delta_insert,
     tombstone_ids,
 )
+
+_query_seq = itertools.count(1)  # Index.query calls in this process: the wl1.query span's seq
 
 
 def _as_key_data(key: jax.Array) -> jax.Array:
@@ -428,26 +432,34 @@ class Index:
         same engine). Invalid result slots are ``ids == -1`` /
         ``dists == +inf`` in every mode.
         """
-        self._validate_query_args(queries, weights)
-        qspec, cfg, _ = self.resolve(spec)
-        _check_probe_reach(cfg, qspec)
-        return engine.query(
-            self.state,
-            self.delta if self.mutable else None,
-            self.tombstones if self.mutable else None,
-            queries,
-            weights,
-            cfg,
-            k=qspec.k,
-            mode=qspec.mode,
-            n_probes=qspec.n_probes,
-            max_flips=qspec.max_flips,
-            impl=qspec.impl,
-            screen_alpha=qspec.screen_alpha,
-            early_exit=qspec.early_exit,
-            exit_group=qspec.exit_group,
-            exit_slack=qspec.exit_slack,
-        )
+        with obs.span(obs.QUERY, seq=next(_query_seq)) as outer:
+            with obs.span(obs.VALIDATE):
+                self._validate_query_args(queries, weights)
+            with obs.span(obs.PLAN):  # the probe reach needs the resolved config
+                qspec, cfg, _ = self.resolve(spec)
+                _check_probe_reach(cfg, qspec)
+            outer.set_metadata(mode=qspec.mode, b=queries.shape[0], k=qspec.k)
+            with obs.span(obs.DISPATCH) as dispatch:
+                before = engine_cache_size()
+                result = engine.query(
+                    self.state,
+                    self.delta if self.mutable else None,
+                    self.tombstones if self.mutable else None,
+                    queries,
+                    weights,
+                    cfg,
+                    k=qspec.k,
+                    mode=qspec.mode,
+                    n_probes=qspec.n_probes,
+                    max_flips=qspec.max_flips,
+                    impl=qspec.impl,
+                    screen_alpha=qspec.screen_alpha,
+                    early_exit=qspec.early_exit,
+                    exit_group=qspec.exit_group,
+                    exit_slack=qspec.exit_slack,
+                )
+                dispatch.set_metadata(compiled=engine_cache_size() - before)
+        return result
 
     def explain(self, queries: jax.Array, weights: jax.Array, spec=QuerySpec()):
         """Run ``query`` and return a :class:`~repro.api.planner.QueryReport`

@@ -31,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import transforms
 from repro.core.index import (
     ALSHIndex,
@@ -72,14 +73,15 @@ def probe_keys(
     streamed early-exit tail consumes this contract to visit windows
     quality-major instead of table-major.
     """
-    if mode == "multiprobe":
-        from repro.core.multiprobe import multiprobe_keys_for
+    with obs.scope(obs.PROJECT):
+        if mode == "multiprobe":
+            from repro.core.multiprobe import multiprobe_keys_for
 
-        return multiprobe_keys_for(
-            state, queries, weights, cfg, n_probes, max_flips, with_ranks=with_ranks
-        )
-    qlevels = transforms.discretize(queries, cfg.space)
-    keys = _keys_for(qlevels, weights, state.tables, cfg, state.mixers, impl=impl)
+            return multiprobe_keys_for(
+                state, queries, weights, cfg, n_probes, max_flips, with_ranks=with_ranks
+            )
+        qlevels = transforms.discretize(queries, cfg.space)
+        keys = _keys_for(qlevels, weights, state.tables, cfg, state.mixers, impl=impl)
     keys = keys[:, :, None]  # (b, L, 1)
     if not with_ranks:
         return keys
@@ -150,24 +152,28 @@ def execute(
     from repro import quant
     from repro.kernels import ops
 
-    blocks = [s.emit(queries, weights) for s in sources]
-    cand = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
-    if len(sources) == 1 and sources[0].pre_deduped:
-        n_candidates = jnp.sum(cand < n_valid, axis=1).astype(jnp.int32)
-    else:
-        cand, n_candidates = _dedupe_candidates(cand, n_valid)
+    with obs.scope(obs.WINDOW):
+        blocks = [s.emit(queries, weights) for s in sources]
+        cand = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+    with obs.scope(obs.DEDUPE):
+        if len(sources) == 1 and sources[0].pre_deduped:
+            n_candidates = jnp.sum(cand < n_valid, axis=1).astype(jnp.int32)
+        else:
+            cand, n_candidates = _dedupe_candidates(cand, n_valid)
     keep = quant.screen_keep(k, screen_alpha, cand.shape[1])  # static int
     if keep:
-        qp, wp = quant.proxy_query(queries, weights, main_data.dtype, scales)
-        _, surv = ops.gather_rerank_topk(
-            main_data, cand, qp, wp, keep, delta=delta_data
+        with obs.scope(obs.SCREEN):
+            qp, wp = quant.proxy_query(queries, weights, main_data.dtype, scales)
+            _, surv = ops.gather_rerank_topk(
+                main_data, cand, qp, wp, keep, delta=delta_data
+            )
+            # survivors come back -1-padded; remap to the candidate sentinel
+            # the rerank expects (so invalid slots stay invalid, never row 0)
+            cand = jnp.where(surv >= 0, surv, n_valid).astype(jnp.int32)
+    with obs.scope(obs.RERANK):
+        dists, ids = ops.gather_rerank_topk(
+            main_data, cand, queries, weights, k, delta=delta_data, scales=scales
         )
-        # survivors come back -1-padded; remap to the candidate sentinel the
-        # rerank expects (so invalid slots stay invalid, never row 0)
-        cand = jnp.where(surv >= 0, surv, n_valid).astype(jnp.int32)
-    dists, ids = ops.gather_rerank_topk(
-        main_data, cand, queries, weights, k, delta=delta_data, scales=scales
-    )
     return QueryResult(dists=dists, ids=ids, n_candidates=n_candidates)
 
 
@@ -196,19 +202,20 @@ def execute_streamed(
     """
     from repro.engine import stream
 
-    return stream.stream_topk(
-        state,
-        delta,
-        tombstones,
-        queries,
-        weights,
-        cfg,
-        keys,
-        k,
-        scales=state.scales,
-        exit_group=exit_group,
-        exit_slack=exit_slack,
-    )
+    with obs.scope(obs.STREAM):
+        return stream.stream_topk(
+            state,
+            delta,
+            tombstones,
+            queries,
+            weights,
+            cfg,
+            keys,
+            k,
+            scales=state.scales,
+            exit_group=exit_group,
+            exit_slack=exit_slack,
+        )
 
 
 def dispatch(
@@ -253,13 +260,14 @@ def dispatch(
             from repro import quant
             from repro.kernels import ops
 
-            table = (
-                state.data
-                if state.data.dtype == jnp.float32
-                else quant.decode_table(state.data, state.scales)
-            )
-            dists, ids = ops.wl1_scan_topk(table, queries, weights, k)
-            n_candidates = jnp.full(queries.shape[0], n_main, jnp.int32)
+            with obs.scope(obs.EXACT_SCAN):
+                table = (
+                    state.data
+                    if state.data.dtype == jnp.float32
+                    else quant.decode_table(state.data, state.scales)
+                )
+                dists, ids = ops.wl1_scan_topk(table, queries, weights, k)
+                n_candidates = jnp.full(queries.shape[0], n_main, jnp.int32)
             return QueryResult(dists=dists, ids=ids, n_candidates=n_candidates)
         if tombstones is None:
             tombstones = jnp.zeros((n_main + cap,), bool)

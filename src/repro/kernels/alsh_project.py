@@ -113,5 +113,6 @@ def alsh_project_pallas(
         out_specs=pl.BlockSpec((BN, BH), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, hp_), jnp.float32),
         interpret=interpret,
+        name="alsh_project_pallas",
     )(levels_p, weights_p, folded_p)
     return out[:n, :H]

@@ -279,10 +279,11 @@ def _run_id_blocks(call, ids, q3, w3, kp, sentinel, max_ids, mult=1):
     return out_d.reshape(nb * bq, kp)[:b], out_i.reshape(nb * bq, kp)[:b]
 
 
-def _topk_call(kernel, tables, specs, grid_of, kp, acc_width, interpret, prefix=()):
-    """One bounded-id pallas_call: ``prefix`` operands (decode scales) and
-    the row ``tables`` follow the scalar-prefetched ids; q, w and the
-    carried top-k buffer come last. The buffer is aliased in place."""
+def _topk_call(kernel, tables, specs, grid_of, kp, acc_width, interpret, name, prefix=()):
+    """One bounded-id pallas_call named ``name``: ``prefix`` operands
+    (decode scales) and the row ``tables`` follow the scalar-prefetched
+    ids; q, w and the carried top-k buffer come last. The buffer is
+    aliased in place."""
 
     def call(ids, q3, w3, initd, initi):
         bq, pc = ids.shape
@@ -305,6 +306,7 @@ def _topk_call(kernel, tables, specs, grid_of, kp, acc_width, interpret, prefix=
             ),
             input_output_aliases={n_in + 2: 0, n_in + 3: 1},
             interpret=interpret,
+            name=name,
         )(ids, *tables, *prefix, q3, w3, initd, initi)
 
     return call
@@ -364,7 +366,7 @@ def gather_rerank_topk_pallas_blocked(
     kernel = _make_blocked_kernel(cb, n_main=n, n_tot=n_tot, two_seg=delta is not None)
     call = _topk_call(
         kernel, tables, specs, lambda bq, p, nd: (bq, p // cb, nd), kp, cb, interpret,
-        prefix=(_row_view(sc.reshape(1, d), dp),),
+        "gather_rerank_topk_pallas_blocked", prefix=(_row_view(sc.reshape(1, d), dp),),
     )
     out_d, out_i = _run_id_blocks(
         call, ids_p, _row_view(queries.astype(jnp.float32), dp),
@@ -430,7 +432,10 @@ def gather_rerank_topk_pallas(
             _row_view(data, dp),
             _row_view(delta.astype(data.dtype).astype(jnp.float32), dp),
         )
-    call = _topk_call(kernel, tables, specs, lambda bq, p, nd: (bq, p, nd), kp, 1, interpret)
+    call = _topk_call(
+        kernel, tables, specs, lambda bq, p, nd: (bq, p, nd), kp, 1, interpret,
+        "gather_rerank_topk_pallas",
+    )
     out_d, out_i = _run_id_blocks(
         call, ids.astype(jnp.int32), _row_view(queries.astype(jnp.float32), dp),
         _row_view(weights.astype(jnp.float32), dp), kp, n_tot, max_ids,

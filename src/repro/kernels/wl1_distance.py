@@ -74,6 +74,7 @@ def wl1_scan_pallas(
         out_specs=pl.BlockSpec((BQ, BNV), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), jnp.float32),
         interpret=interpret,
+        name="wl1_distance_scan_pallas",
     )(data_p, q_p, w_p)
     return out[:b, :n]
 
@@ -123,5 +124,6 @@ def wl1_rerank_pallas(
         out_specs=pl.BlockSpec((None, 1, BC), lambda i, j, k: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((b, 1, cp), jnp.float32),
         interpret=interpret,
+        name="wl1_distance_rerank_pallas",
     )(pts_p, q_p, w_p)
     return out[:, 0, :C]

@@ -151,6 +151,7 @@ def wl1_scan_topk_pallas(
         ),
         scratch_shapes=[pltpu.VMEM((BQ, BNV), jnp.float32)],
         interpret=interpret,
+        name="wl1_scan_topk_pallas",
     )(data_p, q_p, w_p)
     out_d, out_i = out_d[:b, :k], out_i[:b, :k]
     # invalid-slot contract (QueryResult): ids == -1 ⇔ dists == +inf — a row

@@ -11,11 +11,13 @@ one process at a time may load the TPU library, and the test workers all
 import this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import alsh_project, gather_rerank, wl1_topk
+from repro.kernels import alsh_project, gather_rerank, wl1_distance, wl1_topk
 
 N, D, B, P, K = 1_000_000, 128, 1024, 4096, 10
 H, M1 = 12 * 32, 33  # K·L hash functions; M+1 levels
@@ -102,3 +104,36 @@ def test_gather_splits_ids_past_the_smem_bound():
     bq, pc = gather_rerank._id_blocks(B, P, gather_rerank.MAX_PREFETCH_IDS,
                                       gather_rerank.CBLK)
     assert bq * pc <= gather_rerank.MAX_PREFETCH_IDS and pc % gather_rerank.CBLK == 0
+
+
+# the name each kernel states with ``pallas_call(name=...)``: the trace
+# reduction of the benchmark finds the kernels by these names
+PINNED = {
+    "gather_f32": "gather_rerank_topk_pallas",
+    "gather_two_segment": "gather_rerank_topk_pallas",
+    "gather_int8_blocked": "gather_rerank_topk_pallas_blocked",
+    "alsh_project": "alsh_project_pallas",
+    "wl1_scan_topk": "wl1_scan_topk_pallas",
+    "wl1_distance_scan": "wl1_distance_scan_pallas",
+    "wl1_distance_rerank": "wl1_distance_rerank_pallas",
+}
+NAMED_CASES = {
+    **CASES,
+    "wl1_distance_scan": (
+        lambda x, q, w: wl1_distance.wl1_scan_pallas(x, q, w),
+        [((65536, D), jnp.float32), ((64, D), jnp.float32), ((64, D), jnp.float32)],
+    ),
+    "wl1_distance_rerank": (
+        lambda p, q, w: wl1_distance.wl1_rerank_pallas(p, q, w),
+        [((64, 512, D), jnp.float32), ((64, D), jnp.float32), ((64, D), jnp.float32)],
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PINNED))
+def test_kernel_carries_its_pinned_name(one_chip, case):
+    fn, shapes = NAMED_CASES[case]
+    text = _compile(fn, *(jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                          for s, dt in shapes)).as_text()
+    calls = re.findall(r"%(\S+?)(?:\.\d+)? = .*custom-call\(.*tpu_custom_call", text)
+    assert calls and set(calls) == {PINNED[case]}, calls

@@ -17,6 +17,7 @@ benchmarking (``chunked`` only exists for the fused top-k ops).
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import alsh_project as _proj
 from repro.kernels import gather_rerank as _gr
@@ -91,6 +92,24 @@ def wl1_scan_topk(
     if mode == "chunked":
         return _topk.wl1_scan_topk_chunked(data, queries, weights, k)
     return _ref.wl1_scan_topk(data, queries, weights, k)
+
+
+def wl1_scan_topk_merge_share(
+    data: jax.Array,
+    queries: jax.Array,
+    weights: jax.Array,
+    k: int,
+    force: str | None = None,
+) -> jax.Array:
+    """Share of (query block, row block) tiles whose top-k merge ran in the
+    Pallas exact scan: merges / (query blocks × row blocks), a float32
+    scalar. ``force="interpret"`` reads it off the chip."""
+    mode = force or ("pallas" if _on_tpu() else "interpret")
+    _, _, merges = _topk.wl1_scan_topk_pallas(
+        data, queries, weights, k, interpret=mode == "interpret", count_merges=True
+    )
+    row_blocks = -(-data.shape[0] // _topk.BNV)
+    return merges.sum() / jnp.float32(merges.shape[0] * row_blocks)
 
 
 def gather_rerank_topk(

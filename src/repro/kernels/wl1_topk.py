@@ -19,6 +19,23 @@ matching ``lax.top_k`` order on exact equality. Rows padded past n enter with
 +inf and id -1; queries short of k valid rows return (+inf, -1) tails —
 identical semantics to the materializing oracle.
 
+The merge is gated. Its k dependent cross-lane selections cost several
+times the block's distances, and in rows stored in an order unrelated to the
+query almost no block holds a row nearer than the running k-th (row block j
+holds one of the top k with probability about k/j). So a second VMEM scratch
+keeps each query's k-th distance, ``top_d[:, k-1]`` (+inf until k rows are
+in), replicated across lanes and rewritten only by a merge; a tile (BQ
+queries × BNV rows) merges only when some block distance is not >= its
+query's k-th. Skipping leaves the answer bit for bit: the running top-k is
+ascending, and the merge keeps ties in [prev ‖ block] order, so a block whose
+every distance is >= the k-th leaves (top_d, top_i) unchanged. Queries of a
+merging tile with no such row pass through the merge unchanged. The gate is
+one compare and one cross-lane reduction to a scalar a tile. The input it
+cannot help is rows stored by descending distance to every query of a tile:
+there every tile merges and the gate is pure cost. ``count_merges=True``
+adds an output that counts the merges per query block
+(``ops.wl1_scan_topk_merge_share``).
+
 ``wl1_scan_topk_chunked`` is the same algorithm in pure jnp (a fori_loop over
 row chunks with a top_k merge) — the CPU production path: the working set
 stays cache-sized instead of a (b, n) spill.
@@ -78,7 +95,9 @@ def _merge_topk(top_d, top_i, blk_d, blk_i, k: int):
     return new_d, new_i
 
 
-def _scan_topk_kernel(data_ref, q_ref, w_ref, outd_ref, outi_ref, acc_ref, *, k: int, n: int):
+def _scan_topk_kernel(data_ref, q_ref, w_ref, outd_ref, outi_ref, *refs, k: int, n: int):
+    # refs: [merge-count output,] block-distance accumulator, k-th distances
+    cnt_ref, acc_ref, kth_ref = refs if len(refs) == 3 else (None, *refs)
     j = pl.program_id(1)
     kd = pl.program_id(2)
     nd = pl.num_programs(2)
@@ -87,6 +106,9 @@ def _scan_topk_kernel(data_ref, q_ref, w_ref, outd_ref, outi_ref, acc_ref, *, k:
     def _init_topk():
         outd_ref[...] = jnp.full_like(outd_ref, jnp.inf)
         outi_ref[...] = jnp.full_like(outi_ref, -1)
+        kth_ref[...] = jnp.full_like(kth_ref, jnp.inf)
+        if cnt_ref is not None:
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     diff = jnp.abs(data_ref[...][None, :, :] - q_ref[...][:, None, :])  # (BQ, BNV, BDV)
     partial = jnp.sum(w_ref[...][:, None, :] * diff, axis=-1)  # (BQ, BNV)
@@ -100,18 +122,27 @@ def _scan_topk_kernel(data_ref, q_ref, w_ref, outd_ref, outi_ref, acc_ref, *, k:
         acc_ref[...] += partial
 
     @pl.when(kd == nd - 1)
-    def _merge():
+    def _last_chunk():
         row0 = j * BNV
         ids = row0 + jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)  # (BQ, BNV)
         in_bounds = ids < n
         blk_d = jnp.where(in_bounds, acc_ref[...], jnp.inf)
-        blk_i = jnp.where(in_bounds, ids, -1)
-        new_d, new_i = _merge_topk(outd_ref[...], outi_ref[...], blk_d, blk_i, k)
-        outd_ref[...] = new_d
-        outi_ref[...] = new_i
+        # the gate: skip the merge when every block distance is >= its
+        # query's k-th (then the merge would change nothing)
+        stays = jnp.where(blk_d >= kth_ref[...], 1.0, 0.0)
+
+        @pl.when(jnp.min(stays) == 0.0)
+        def _merge():
+            blk_i = jnp.where(in_bounds, ids, -1)
+            new_d, new_i = _merge_topk(outd_ref[...], outi_ref[...], blk_d, blk_i, k)
+            outd_ref[...] = new_d
+            outi_ref[...] = new_i
+            kth_ref[...] = jnp.broadcast_to(new_d[:, k - 1 : k], kth_ref.shape)
+            if cnt_ref is not None:
+                cnt_ref[...] += 1
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "interpret", "count_merges"))
 def wl1_scan_topk_pallas(
     data: jax.Array,
     queries: jax.Array,
@@ -119,8 +150,13 @@ def wl1_scan_topk_pallas(
     k: int,
     *,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """data (n, d), queries (b, d), weights (b, d) -> ((b, k) dists, (b, k) ids)."""
+    count_merges: bool = False,
+) -> tuple[jax.Array, ...]:
+    """data (n, d), queries (b, d), weights (b, d) -> ((b, k) dists, (b, k) ids).
+
+    With ``count_merges`` a third output, (ceil(b / BQ),) int32, counts the
+    row blocks whose merge ran, for each query block.
+    """
     n, d = data.shape
     b, _ = queries.shape
     kp = -k % LANE + k  # top-k buffer lane-aligned
@@ -133,7 +169,16 @@ def wl1_scan_topk_pallas(
     bp, dp = q_p.shape
     np_ = data_p.shape[0]
     grid = (bp // BQ, np_ // BNV, dp // BDV)
-    out_d, out_i = pl.pallas_call(
+    topk_spec = pl.BlockSpec((BQ, kp), lambda i, j, kd: (i, 0))
+    out_specs = [topk_spec, topk_spec]
+    out_shape = [
+        jax.ShapeDtypeStruct((bp, kp), jnp.float32),
+        jax.ShapeDtypeStruct((bp, kp), jnp.int32),
+    ]
+    if count_merges:
+        out_specs.append(pl.BlockSpec((BQ, LANE), lambda i, j, kd: (i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bp, LANE), jnp.int32))
+    outs = pl.pallas_call(
         functools.partial(_scan_topk_kernel, k=k, n=n),
         grid=grid,
         in_specs=[
@@ -141,23 +186,20 @@ def wl1_scan_topk_pallas(
             pl.BlockSpec((BQ, BDV), lambda i, j, kd: (i, kd)),
             pl.BlockSpec((BQ, BDV), lambda i, j, kd: (i, kd)),
         ],
-        out_specs=(
-            pl.BlockSpec((BQ, kp), lambda i, j, kd: (i, 0)),
-            pl.BlockSpec((BQ, kp), lambda i, j, kd: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((bp, kp), jnp.float32),
-            jax.ShapeDtypeStruct((bp, kp), jnp.int32),
-        ),
-        scratch_shapes=[pltpu.VMEM((BQ, BNV), jnp.float32)],
+        out_specs=tuple(out_specs),
+        out_shape=tuple(out_shape),
+        scratch_shapes=[pltpu.VMEM((BQ, BNV), jnp.float32), pltpu.VMEM((BQ, BNV), jnp.float32)],
         interpret=interpret,
         name="wl1_scan_topk_pallas",
     )(data_p, q_p, w_p)
-    out_d, out_i = out_d[:b, :k], out_i[:b, :k]
+    out_d, out_i = outs[0][:b, :k], outs[1][:b, :k]
     # invalid-slot contract (QueryResult): ids == -1 ⇔ dists == +inf — a row
     # whose distance overflowed to +inf reports "not found", matching the
     # _topk_ascending paths bit-for-bit
-    return out_d, jnp.where(jnp.isfinite(out_d), out_i, -1)
+    out_i = jnp.where(jnp.isfinite(out_d), out_i, -1)
+    if count_merges:
+        return out_d, out_i, outs[2][::BQ, 0]
+    return out_d, out_i
 
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk"))

@@ -199,3 +199,81 @@ def test_scan_topk_positive_weights_ascending(impl, rng):
     assert np.all(np.diff(d, axis=1) >= -1e-6)
     assert np.all(d >= -1e-6)
     assert np.all(np.asarray(i) >= 0)
+
+
+# The exact scan's gated merge: a (query block, row block) tile merges only
+# when some block distance is below its query's k-th. Integer-valued rows,
+# queries and weights make every distance exact in float32, so the kernel
+# must match the oracle bit for bit whatever the summation order.
+GATE_N, GATE_B, GATE_D = 700, 12, 20  # 6 row blocks, the last partial; 2 query blocks
+
+
+def _gate_inputs(order, n=GATE_N, b=GATE_B, d=GATE_D, seed=0):
+    """Rows ordered by distance to every query: ``ascending``, ``descending``
+    or ``random``. Row r of the ascending table is r in every coordinate and
+    each query lies below 0, so each row is strictly farther than the last."""
+    rng = np.random.default_rng(seed)
+    q = -rng.integers(0, 6, (b, d)).astype(np.float32)
+    w = rng.integers(1, 4, (b, d)).astype(np.float32)
+    rows = np.repeat(np.arange(n, dtype=np.float32)[:, None], d, axis=1)
+    if order == "descending":
+        rows = rows[::-1]
+    elif order == "random":
+        rows = rows[rng.permutation(n)]
+    return jnp.asarray(np.ascontiguousarray(rows)), jnp.asarray(q), jnp.asarray(w)
+
+
+def _assert_bit_identical(got, want):
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("order", ["random", "ascending", "descending"])
+def test_scan_topk_gate_row_order(order):
+    data, q, w = _gate_inputs(order)
+    got = wl1_scan_topk_pallas(data, q, w, 10, interpret=True)
+    _assert_bit_identical(got, ref.wl1_scan_topk(data, q, w, 10))
+
+
+@pytest.mark.parametrize("copy_to", [1, 3, 5])  # a later row block; 5 is the partial last
+def test_scan_topk_gate_ties_keep_earlier_ids(copy_to):
+    """Exact duplicates of the top-k rows in a later row block tie with
+    their originals and must not displace the earlier ids."""
+    rng = np.random.default_rng(copy_to)
+    n, b, d, k = GATE_N, GATE_B, GATE_D, 10
+    data = rng.integers(8, 16, (n, d)).astype(np.float32)
+    data[:128] = rng.integers(0, 4, (128, d))  # block 0 holds every top-k row
+    lo = 128 * copy_to
+    data[lo : min(lo + 128, n)] = data[: min(128, n - lo)]
+    q = rng.integers(0, 4, (b, d)).astype(np.float32)
+    w = rng.integers(1, 4, (b, d)).astype(np.float32)
+    data, q, w = jnp.asarray(data), jnp.asarray(q), jnp.asarray(w)
+    got = wl1_scan_topk_pallas(data, q, w, k, interpret=True)
+    _assert_bit_identical(got, ref.wl1_scan_topk(data, q, w, k))
+    for ids in np.asarray(got[1]).tolist():  # a copy comes only after its original
+        for pos, i in enumerate(ids):
+            assert i < 128 or (i - lo) in ids[:pos]
+
+
+@pytest.mark.parametrize("k,n", [(1, GATE_N), (128, GATE_N), (200, 150)])  # LANE; k > n
+@pytest.mark.parametrize("order", ["random", "descending"])
+def test_scan_topk_gate_k(k, n, order):
+    data, q, w = _gate_inputs(order, n=n)
+    got = wl1_scan_topk_pallas(data, q, w, k, interpret=True)
+    _assert_bit_identical(got, ref.wl1_scan_topk(data, q, w, k))
+
+
+@pytest.mark.parametrize("k", [1, 10, 128])
+@pytest.mark.parametrize("order", ["ascending", "descending"])
+def test_scan_topk_merge_count(order, k):
+    """Ascending rows merge once per query block (the first row block fills
+    the top-k, and nothing after it is nearer); descending rows merge in
+    every row block. The counting variant returns the served answer."""
+    data, q, w = _gate_inputs(order)
+    d_, i_, merges = wl1_scan_topk_pallas(data, q, w, k, interpret=True, count_merges=True)
+    row_blocks = -(-GATE_N // 128)
+    want = 1 if order == "ascending" else row_blocks
+    assert np.asarray(merges).tolist() == [want] * (-(-GATE_B // 8))
+    _assert_bit_identical((d_, i_), wl1_scan_topk_pallas(data, q, w, k, interpret=True))
+    share = ops.wl1_scan_topk_merge_share(data, q, w, k, force="interpret")
+    assert float(share) == pytest.approx(want / row_blocks)

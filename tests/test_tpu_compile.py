@@ -86,6 +86,11 @@ CASES = {
         lambda x, q, w: wl1_topk.wl1_scan_topk_pallas(x, q, w, K),
         [((N, D), jnp.float32), ((64, D), jnp.float32), ((64, D), jnp.float32)],
     ),
+    # the exact scan with its merge counter, as ops.wl1_scan_topk_merge_share runs it
+    "wl1_scan_topk_count_merges": (
+        lambda x, q, w: wl1_topk.wl1_scan_topk_pallas(x, q, w, K, count_merges=True),
+        [((N, D), jnp.float32), ((64, D), jnp.float32), ((64, D), jnp.float32)],
+    ),
 }
 
 
@@ -114,6 +119,7 @@ PINNED = {
     "gather_int8_blocked": "gather_rerank_topk_pallas_blocked",
     "alsh_project": "alsh_project_pallas",
     "wl1_scan_topk": "wl1_scan_topk_pallas",
+    "wl1_scan_topk_count_merges": "wl1_scan_topk_pallas",
     "wl1_distance_scan": "wl1_distance_scan_pallas",
     "wl1_distance_rerank": "wl1_distance_rerank_pallas",
 }

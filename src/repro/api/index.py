@@ -414,6 +414,20 @@ class Index:
             self.plans.setdefault(quality, ladder[0])
         return ladder
 
+    def _screen_keep(self, qspec: QuerySpec, cfg: IndexConfig) -> int:
+        """Survivors of the quantized screen a query under ``qspec`` runs,
+        as the engine sizes it; 0 where no screen runs (f32 storage, an
+        exact scan, α = 0, or survivors covering every candidate slot)."""
+        from repro import quant
+
+        if qspec.mode == "exact" or self.state.data.dtype == jnp.float32:
+            return 0
+        p_slots = qspec.n_probes if qspec.mode == "multiprobe" else 1
+        n_slots = cfg.L * p_slots * cfg.max_candidates + (
+            self.delta.capacity if self.mutable else 0
+        )
+        return quant.screen_keep(qspec.k, qspec.screen_alpha, n_slots)
+
     def query(self, queries: jax.Array, weights: jax.Array, spec=QuerySpec()) -> QueryResult:
         """Batched k-NN under d_w^l1; ``spec`` picks the execution strategy.
 
@@ -438,7 +452,9 @@ class Index:
             with obs.span(obs.PLAN):  # the probe reach needs the resolved config
                 qspec, cfg, _ = self.resolve(spec)
                 _check_probe_reach(cfg, qspec)
-            outer.set_metadata(mode=qspec.mode, b=queries.shape[0], k=qspec.k)
+            outer.set_metadata(mode=qspec.mode, b=queries.shape[0], k=qspec.k,
+                               storage=self.config.storage,
+                               screen_keep=self._screen_keep(qspec, cfg))
             with obs.span(obs.DISPATCH) as dispatch:
                 before = engine_cache_size()
                 result = engine.query(
@@ -514,21 +530,9 @@ class Index:
         # Screening gathers every unique candidate once at the ENCODED row
         # width; the exact rerank then re-gathers only the survivors (all
         # candidates when the screen is statically off).
-        from repro import quant
-
         n_cand = np.asarray(res.n_candidates, dtype=np.int64)
         row_bytes = self.state.data.dtype.itemsize * cfg.d
-        screening = (
-            qspec.mode != "exact" and self.state.data.dtype != jnp.float32
-        )
-        if screening:
-            p_slots = qspec.n_probes if qspec.mode == "multiprobe" else 1
-            n_slots = cfg.L * p_slots * cfg.max_candidates + (
-                self.delta.capacity if self.mutable else 0
-            )
-            keep = quant.screen_keep(qspec.k, qspec.screen_alpha, n_slots)
-        else:
-            keep = 0
+        keep = self._screen_keep(qspec, cfg)
         rows_screened = n_cand if keep else np.zeros_like(n_cand)
         rows_reranked = np.minimum(n_cand, keep) if keep else n_cand
         bytes_gathered = (rows_screened + rows_reranked) * row_bytes

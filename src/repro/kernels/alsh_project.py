@@ -14,6 +14,13 @@ query weights into the one-hot, and issue a dense
 
 matmul, accumulating over d-chunks via the innermost grid dimension. Tables
 tile VMEM as (bh, dc, M+1); MXU dims (bn, bh) are 128-aligned by the wrapper.
+
+The contraction runs at ``Precision.HIGHEST``: at the default precision the
+MXU rounds float32 operands to bfloat16, which moves a projection by up to
+2^-8 of its terms and flips the sign bit (theta) or bucket (l2) of the ones
+near a boundary — keys a float32 implementation of the same hash does not
+give. The one-hot is exact in bfloat16, but the tables and the weighted
+one-hot of the query side are not.
 """
 
 from __future__ import annotations
@@ -53,6 +60,7 @@ def _project_kernel(levels_ref, weights_ref, folded_ref, out_ref, *, weighted: b
         lhs,
         rhs,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )  # (BN, BH)
 

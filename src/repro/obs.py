@@ -22,7 +22,7 @@ import jax
 PREFIX = "wl1."
 
 # host spans (Index.query and the collector)
-QUERY = "query"  # args: seq, mode, b, k
+QUERY = "query"  # args: seq, mode, b, k, storage, screen_keep
 VALIDATE = "query.validate"
 PLAN = "query.plan"
 DISPATCH = "query.dispatch"  # args: compiled

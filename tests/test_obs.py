@@ -76,6 +76,29 @@ def test_query_spans_nest_and_carry_args(rng, tmp_path, mode):
     assert repeat[2]["wl1.query.dispatch"]["compiled"] == 0
 
 
+# (storage, spec, the screen's survivors the wl1.query span names)
+TAILS = {
+    "f32_probe": ("f32", QuerySpec(k=5), 0),
+    "f32_probe_alpha": ("f32", QuerySpec(k=5, screen_alpha=2.0), 0),
+    "int8_probe_screen": ("int8", QuerySpec(k=5, screen_alpha=2.0), 10),
+    "int8_probe_no_screen": ("int8", QuerySpec(k=5), 0),
+    "int8_exact": ("int8", QuerySpec(k=5, mode="exact", screen_alpha=2.0), 0),
+}
+
+
+@pytest.mark.parametrize("tail", sorted(TAILS))
+def test_query_span_names_its_tail(rng, tmp_path, tail):
+    """``storage`` and ``screen_keep`` on ``wl1.query`` say which tail the
+    call ran: the screen's survivors, or 0 where no screen runs."""
+    storage, spec, keep = TAILS[tail]
+    index = _index(rng, storage=storage)
+    q, w = _batch(rng, 8)
+    (args, _, _), = _queries(_traced(tmp_path, lambda: index.query(q, w, spec)
+                                     .ids.block_until_ready()))
+    assert args["storage"] == storage
+    assert args["screen_keep"] == keep
+
+
 def test_collector_pass_leaves_one_span(tmp_path):
     was_on = gc.isenabled()
     gc.disable()  # no automatic pass inside the trace: only the one asked for

@@ -81,6 +81,11 @@ CASES = {
         lambda lv, f: alsh_project.alsh_project_pallas(lv, f),
         [((N, D), jnp.int32), ((H, D, M1), jnp.float32)],
     ),
+    # a query batch's weighted hash projection
+    "alsh_project_query": (
+        lambda lv, f, w: alsh_project.alsh_project_pallas(lv, f, w),
+        [((B, D), jnp.int32), ((H, D, M1), jnp.float32), ((B, D), jnp.float32)],
+    ),
     # the exact scan
     "wl1_scan_topk": (
         lambda x, q, w: wl1_topk.wl1_scan_topk_pallas(x, q, w, K),
@@ -118,6 +123,7 @@ PINNED = {
     "gather_two_segment": "gather_rerank_topk_pallas",
     "gather_int8_blocked": "gather_rerank_topk_pallas_blocked",
     "alsh_project": "alsh_project_pallas",
+    "alsh_project_query": "alsh_project_pallas",
     "wl1_scan_topk": "wl1_scan_topk_pallas",
     "wl1_scan_topk_count_merges": "wl1_scan_topk_pallas",
     "wl1_distance_scan": "wl1_distance_scan_pallas",
